@@ -26,7 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
                    acc_scr, *, bk: int, nk: int, G: int, scale: float,
                    window: Optional[int], softcap: Optional[float]):
     ik = pl.program_id(2)
@@ -40,7 +40,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref, m_scr, l_scr,
     q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, D)
     k = k_ref[0, 0].astype(jnp.float32)                  # (bk, D)
     v = v_ref[0, 0].astype(jnp.float32)
-    valid_len = len_ref[0]
+    valid_len = len_ref[pl.program_id(0)]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (G, bk)
@@ -97,22 +97,28 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kernel = functools.partial(
         _decode_kernel, bk=bk, nk=nk, G=G, scale=1.0 / float(np.sqrt(D)),
         window=window, softcap=softcap)
-    out = pl.pallas_call(
-        kernel,
+    # the per-row valid lengths are scalars: they ride in SMEM, ahead of
+    # the grid, instead of as a (1,)-blocked vector Mosaic cannot tile
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik: (b, h, ik, 0)),
-            pl.BlockSpec((1,), lambda b, h, ik: (b,)),
+            pl.BlockSpec((1, 1, G, D), lambda b, h, ik, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, ln: (b, h, ik, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, ik, ln: (b, h, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, ik: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, G, D),
+                               lambda b, h, ik, ln: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, 1), jnp.float32),
             pltpu.VMEM((G, D), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, KV, G, D), q.dtype),
         interpret=interpret,
-    )(qg, kt, vt, valid_len.astype(jnp.int32))
+    )(valid_len.astype(jnp.int32), qg, kt, vt)
     return out.reshape(B, H, D)

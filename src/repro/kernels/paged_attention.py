@@ -21,8 +21,10 @@ Two generalizations over plain flash-decoding:
   ``[i*bt, (i+1)*bt)`` regardless of which pool row backs it, so the
   kernel never sees (and the engine never computes) a contiguous layout.
 
-Grid ``(B, KV, num_table_blocks)``. On non-TPU backends the interpret mode
-runs the identical tiling/masking logic as traced jnp ops.
+Grid ``(B, num_table_blocks)``: each step DMAs one whole pool row, all
+KV heads of it, and updates every head's online-softmax state. On non-TPU
+backends the interpret mode runs the identical tiling/masking logic as
+traced jnp ops.
 """
 from __future__ import annotations
 
@@ -38,10 +40,10 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref, m_scr,
-                  l_scr, acc_scr, *, bt: int, nw: int, G: int, S: int,
-                  scale: float, softcap: Optional[float]):
-    ik = pl.program_id(2)
+def _paged_kernel(tbl_ref, qpos_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+                  l_scr, acc_scr, *, bt: int, nw: int, KV: int, G: int,
+                  S: int, scale: float, softcap: Optional[float]):
+    b, ik = pl.program_id(0), pl.program_id(1)
 
     @pl.when(ik == 0)
     def init():
@@ -49,38 +51,44 @@ def _paged_kernel(tbl_ref, q_ref, k_ref, v_ref, qpos_ref, o_ref, m_scr,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale          # (S*G, D)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bt, D)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)  # (S*G, bt)
-    if softcap:
-        s = softcap * jnp.tanh(s / softcap)
     # logical positions of this tile's keys — the table block index, not
-    # the pool row, carries position
+    # the pool row, carries position. Query row r of the (S*G, D) tile is
+    # chunk token r // G, whose position is read from SMEM.
     kpos = ik * bt + jax.lax.broadcasted_iota(jnp.int32, (S * G, bt), 1)
-    qp = qpos_ref[0]                                      # (S,)
-    qp = jax.lax.broadcast_in_dim(qp, (S, G), (0,)).reshape(S * G)
-    mask = kpos <= qp[:, None]
-    s = jnp.where(mask, s, NEG_INF)
+    row = jax.lax.broadcasted_iota(jnp.int32, (S * G, bt), 0)
+    qp = jnp.full((S * G, bt), qpos_ref[b, 0], jnp.int32)
+    for j in range(1, S):
+        qp = jnp.where(row >= j * G, qpos_ref[b, j], qp)
+    mask = kpos <= qp
 
-    m_prev = m_scr[...]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
-    p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
-    alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0, jnp.exp(m_prev - safe_m))
-    m_scr[...] = m_new
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    for h in range(KV):      # one pool row holds every KV head's tile
+        q = q_ref[0, h].astype(jnp.float32) * scale      # (S*G, D)
+        k = k_ref[0, :, h, :].astype(jnp.float32)         # (bt, D)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        if softcap:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(mask, s, NEG_INF)
+
+        m_prev = m_scr[h]
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        safe_m = jnp.where(m_new <= NEG_INF / 2, 0.0, m_new)
+        p = jnp.where(mask, jnp.exp(s - safe_m), 0.0)
+        alpha = jnp.where(m_prev <= NEG_INF / 2, 0.0,
+                          jnp.exp(m_prev - safe_m))
+        m_scr[h] = m_new
+        l_scr[h] = alpha * l_scr[h] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[h] = alpha * acc_scr[h] + jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(ik == nw - 1)
     def finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        for h in range(KV):
+            l = jnp.maximum(l_scr[h], 1e-30)
+            o_ref[0, h] = (acc_scr[h] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
@@ -104,26 +112,28 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         .reshape(B, KV, S * G, D)
 
     kernel = functools.partial(
-        _paged_kernel, bt=bt, nw=NW, G=G, S=S,
+        _paged_kernel, bt=bt, nw=NW, KV=KV, G=G, S=S,
         scale=1.0 / float(np.sqrt(D)), softcap=softcap)
+    # Mosaic tiles the last two block dims, so every block keeps them
+    # whole: a K/V tile is one full pool row (bt, KV, D) and the kernel
+    # walks its KV heads; per-token positions ride in SMEM with the table.
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                 # the block table
-        grid=(B, KV, NW),
+        num_scalar_prefetch=2,                 # block table, query positions
+        grid=(B, NW),
         in_specs=[
-            pl.BlockSpec((1, 1, S * G, D),
-                         lambda b, h, ik, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, ik, tbl: (tbl[b, ik], 0, h, 0)),
-            pl.BlockSpec((1, bt, 1, D),
-                         lambda b, h, ik, tbl: (tbl[b, ik], 0, h, 0)),
-            pl.BlockSpec((1, S), lambda b, h, ik, tbl: (b, 0)),
+            pl.BlockSpec((1, KV, S * G, D),
+                         lambda b, ik, tbl, qp: (b, 0, 0, 0)),
+            pl.BlockSpec((1, bt, KV, D),
+                         lambda b, ik, tbl, qp: (tbl[b, ik], 0, 0, 0)),
+            pl.BlockSpec((1, bt, KV, D),
+                         lambda b, ik, tbl, qp: (tbl[b, ik], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, S * G, D),
-                               lambda b, h, ik, tbl: (b, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, KV, S * G, D),
+                               lambda b, ik, tbl, qp: (b, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((S * G, 1), jnp.float32),
-            pltpu.VMEM((S * G, 1), jnp.float32),
-            pltpu.VMEM((S * G, D), jnp.float32),
+            pltpu.VMEM((KV, S * G, 1), jnp.float32),
+            pltpu.VMEM((KV, S * G, 1), jnp.float32),
+            pltpu.VMEM((KV, S * G, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -131,7 +141,7 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, S * G, D), q.dtype),
         interpret=interpret,
-    )(tables.astype(jnp.int32), qg, k_pages, v_pages,
-      qpos.astype(jnp.int32))
+    )(tables.astype(jnp.int32), qpos.astype(jnp.int32), qg, k_pages,
+      v_pages)
     return out.reshape(B, KV, S, G, D).transpose(0, 2, 1, 3, 4) \
         .reshape(B, S, H, D)

@@ -3,6 +3,9 @@
   PYTHONPATH=src python -m repro.launch.serve --arch qwen2-7b --smoke \
       --requests 12 --policy lerc
 
+``--layers N`` keeps the published widths and serves the first N layers,
+the depth that fits one chip (qwen2_7b: 14 on a 16 GB v5e).
+
 With ``--arrival`` the run goes through the always-on front door instead
 of the batch loop: requests arrive on a timed trace (Poisson / bursty /
 diurnal, seeded), the chosen ``--scheduler`` divides each step's prefill
@@ -21,28 +24,52 @@ import dataclasses
 import json
 import sys
 import time
+from typing import List, Optional, Union
 
 import jax
 import numpy as np
 
 from .. import configs
 from ..core import POLICIES
-from ..faults import FaultPlan
+from ..faults import FaultInjector, FaultPlan
 from ..models import init_params, model_spec
+from ..models.common import ModelConfig
 from ..obs import TraceRecorder, jsonable
 from ..serve import (BudgetedScheduler, PrefixStore, ServeEngine,
                      ShardedFrontend, TieredKVStore, TracedRequest,
                      latency_stats, play_trace)
+from ..sharding import serve_tp_context
 from ..sim import bursty_arrivals, diurnal_arrivals, poisson_arrivals
+from .compile_cache import use_compile_cache
 
 _ARRIVALS = {"poisson": poisson_arrivals, "bursty": bursty_arrivals,
              "diurnal": diurnal_arrivals}
 
 
-def serve_main(argv=None) -> int:
+@dataclasses.dataclass
+class Server:
+    """Everything ``build_server`` makes from a command line: the parsed
+    arguments, the (depth-cut) model config, the engine or sharded
+    frontend, the seeded prompts, and the fault injector and trace
+    recorder when asked for."""
+    args: argparse.Namespace
+    cfg: ModelConfig
+    engine: Union[ServeEngine, ShardedFrontend]
+    prompts: List[List[int]]
+    injector: Optional[FaultInjector] = None
+    recorder: Optional[TraceRecorder] = None
+
+
+def build_server(argv=None) -> Server:
+    """Parse and validate a command line, then build the model and the
+    engine it describes, with its prompts. ``serve_main`` runs what this
+    returns; ``chip_smoke.py`` drives it step by step."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
+    ap.add_argument("--layers", type=int, default=None, metavar="N",
+                    help="serve only the first N layers of the config; "
+                         "every width stays as published")
     ap.add_argument("--requests", type=int, default=12)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-seq", type=int, default=128)
@@ -170,6 +197,8 @@ def serve_main(argv=None) -> int:
                  "gather plane forced by --no-paged-attention")
     if args.fault_seed is not None and args.fault_plan is None:
         ap.error("--fault-seed overrides a plan's seed; pass --fault-plan")
+    if args.layers is not None and args.layers < 1:
+        ap.error(f"--layers must be at least 1 (got {args.layers})")
     injector = None
     if args.fault_plan is not None:
         try:
@@ -184,9 +213,18 @@ def serve_main(argv=None) -> int:
                          f"{args.shards} (valid: 0..{args.shards - 1})")
         injector = plan.injector()
 
+    use_compile_cache()
     cfg = configs.get(args.arch, smoke=args.smoke)
-    params = init_params(jax.random.key(args.seed), model_spec(cfg),
-                         dtype=cfg.dtype)
+    if args.layers is not None:
+        cfg = cfg.replace(n_layers=args.layers)
+    # under --tp every device holds the whole model: each leaf is made
+    # straight into the replicated placement, never first on device 0
+    kv_shard = serve_tp_context(args.tp) if args.tp > 1 else None
+    if kv_shard is not None:
+        kv_shard.validate(cfg)      # before, not after, the weights exist
+    params = init_params(
+        jax.random.key(args.seed), model_spec(cfg), dtype=cfg.dtype,
+        sharding=None if kv_shard is None else kv_shard.replicated())
     host_bytes = args.host_cache_kb * 1024
     disk_bytes = args.disk_cache_mb * 1024 * 1024
     absolute_kv = set(cfg.layer_pattern) <= {"G", "M"}
@@ -238,7 +276,7 @@ def serve_main(argv=None) -> int:
                           prefill_chunk=args.prefill_chunk,
                           pool_blocks=args.pool_blocks, paged=args.paged,
                           scheduler=scheduler, max_queue=args.max_queue,
-                          tp=args.tp)
+                          kv_shard=kv_shard)
 
     recorder = None
     if args.trace is not None:
@@ -263,6 +301,13 @@ def serve_main(argv=None) -> int:
     prompts = [prefixes[i % n_families]
                + list(rng.integers(0, cfg.vocab, 8))
                for i in range(args.requests)]
+    return Server(args, cfg, eng, prompts, injector, recorder)
+
+
+def serve_main(argv=None) -> int:
+    srv = build_server(argv)
+    args, cfg, eng = srv.args, srv.cfg, srv.engine
+    injector, recorder = srv.injector, srv.recorder
     t0 = time.time()
     report = None
     if args.arrival is not None:
@@ -270,10 +315,10 @@ def serve_main(argv=None) -> int:
                                         args.seed)
         trace = [TracedRequest(t=t, prompt=p, max_new=args.max_new,
                                deadline=args.deadline_ms)
-                 for t, p in zip(times, prompts)]
+                 for t, p in zip(times, srv.prompts)]
         report = play_trace(eng, trace, retry_rejected=args.retry_rejected)
     else:
-        for p in prompts:
+        for p in srv.prompts:
             eng.submit(p, max_new=args.max_new)
         eng.run()
     if args.shards > 1:
@@ -290,7 +335,9 @@ def serve_main(argv=None) -> int:
             m[name] = injector.counters[name]
     paged_on = (all(e.paged for e in eng.shards) if args.shards > 1
                 else eng.paged)
-    print(f"policy={args.policy}  shards={args.shards}  tp={args.tp}  "
+    full_layers = configs.get(args.arch, smoke=args.smoke).n_layers
+    print(f"arch={cfg.arch}  layers={cfg.n_layers}/{full_layers}  "
+          f"policy={args.policy}  shards={args.shards}  tp={args.tp}  "
           f"paged={'on' if paged_on else 'off'}  "
           f"scheduler={args.scheduler}"
           + (f"  arrival={args.arrival}@{args.arrival_rate}"

@@ -12,6 +12,7 @@ Every parameter is declared once as a ``ParamSpec`` carrying its shape and
 from __future__ import annotations
 
 import dataclasses
+import functools
 import zlib
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional, Tuple
@@ -128,24 +129,46 @@ def tree_paths(tree, prefix=()):
         yield prefix, tree
 
 
-def init_params(rng: jax.Array, spec_tree, dtype=jnp.bfloat16):
+@functools.lru_cache(maxsize=None)
+def _leaf_filler(sharding):
+    """One jitted leaf initializer per output placement. Jitting fuses
+    the float32 draw with the cast, so a leaf is written straight out in
+    its own dtype (no float32 temporary of the whole stacked leaf) and,
+    with ``sharding``, straight into its final placement."""
+
+    def fill(key, shape, init, scale, dtype):
+        if init == "zeros":
+            return jnp.zeros(shape, dtype)
+        if init == "ones":
+            return jnp.ones(shape, dtype)
+        x = jax.random.normal(key, shape, jnp.float32)
+        # a no-op rounding that keeps XLA from folding ``scale`` into the
+        # sampler's own sqrt(2) factor: the product then rounds exactly
+        # as the unfused ``normal(...) * scale`` does
+        x = jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=23)
+        return (x * scale).astype(dtype)
+
+    return jax.jit(fill, static_argnums=(1, 2, 3, 4), out_shardings=sharding)
+
+
+def init_params(rng: jax.Array, spec_tree, dtype=jnp.bfloat16,
+                sharding=None):
     """Deterministic init: each leaf's key is folded from its path hash, so
-    adding/removing parameters never reshuffles the others."""
+    adding/removing parameters never reshuffles the others. ``sharding``
+    places every leaf there as it is made (e.g. replicated over a serve
+    mesh), so no device ever holds a leaf twice."""
+    fill = _leaf_filler(sharding)
 
     def init_leaf(path, s: ParamSpec):
-        d = s.dtype or dtype
-        if s.init == "zeros":
-            return jnp.zeros(s.shape, d)
-        if s.init == "ones":
-            return jnp.ones(s.shape, d)
         # crc32, not hash(): stable across processes (PYTHONHASHSEED)
         key = jax.random.fold_in(
             rng, zlib.crc32("/".join(path).encode()) % (2 ** 31))
+        scale = s.scale
         if s.init == "scaled":          # fan-in scaled
             fan_in = s.shape[-2] if len(s.shape) >= 2 else s.shape[-1]
-            return (jax.random.normal(key, s.shape, jnp.float32)
-                    * (1.0 / np.sqrt(fan_in))).astype(d)
-        return (jax.random.normal(key, s.shape, jnp.float32) * s.scale).astype(d)
+            scale = 1.0 / np.sqrt(fan_in)
+        return fill(key, s.shape, s.init, float(scale),
+                    jnp.dtype(s.dtype or dtype))
 
     def walk(tree, prefix=()):
         if isinstance(tree, dict):

@@ -212,7 +212,6 @@ def _paged_write_attend_tp(cfg: ModelConfig, kv_shard, q, k, v, kp, vp,
     would instead reduce partial wo products in mesh order, perturbing
     bf16 rounding). The pages come back head-sharded, matching the pool's
     committed layout, so the engine's donated step keeps them in place."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
 
     ax = kv_shard.axis
@@ -225,11 +224,11 @@ def _paged_write_attend_tp(cfg: ModelConfig, kv_shard, q, k, v, kp, vp,
         out = jax.lax.all_gather(out, ax, axis=2, tiled=True)
         return out, ck, cv
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=kv_shard.mesh,
         in_specs=(heads, heads, heads, heads, heads, repl, repl, repl),
         out_specs=(PartitionSpec(), heads, heads),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, kp, vp, tables, lens, cache_pos)
 
 

@@ -219,6 +219,7 @@ class ServeEngine:
                      (lambda x: jax.device_put(jnp.asarray(x),
                                                kv_shard.replicated())))
         if kv_shard is not None:
+            # a no-op for params made in place (``init_params(sharding=)``)
             params = jax.device_put(params, kv_shard.replicated())
         self.cfg = cfg
         self.params = params

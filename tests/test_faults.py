@@ -488,6 +488,7 @@ BAD_FLAG_COMBOS = [
     ["--fault-seed", "3"],                      # seed without a plan
     ["--fault-plan", "/nonexistent/plan.json"],  # unreadable plan
     ["--tp", "2", "--no-paged-attention"],      # TP needs the paged plane
+    ["--layers", "0"],                          # a model needs a layer
 ]
 
 

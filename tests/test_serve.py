@@ -1,4 +1,6 @@
 """Prefix store (LERC on KV chains) and serve-engine integration tests."""
+from pathlib import Path
+
 import jax
 import numpy as np
 import pytest
@@ -119,3 +121,75 @@ def test_engine_continuous_batching_isolation():
     rb = duo.submit(p2, max_new=4)
     duo.run()
     assert ra.generated == rs.generated
+
+
+# ---------------------------------------------------------------------------
+# launch: depth cut, jitted parameter init, compile cache
+# ---------------------------------------------------------------------------
+
+def test_launch_layers_cuts_depth_only(capsys, monkeypatch):
+    """--layers N serves the first N layers at the config's own widths and
+    says so in the summary line."""
+    import repro.launch.serve as launch
+    from repro.launch.serve import build_server, serve_main
+    # the persistent compile cache is process-wide; keep it off in tests
+    monkeypatch.setattr(launch, "use_compile_cache", lambda: None)
+    argv = ["--arch", "qwen2_7b", "--smoke", "--layers", "2",
+            "--requests", "4", "--slots", "2", "--max-seq", "64",
+            "--cache-kb", "64", "--max-new", "2"]
+    srv = build_server(argv)
+    full = configs.get("qwen2_7b", smoke=True)
+    assert srv.cfg == full.replace(n_layers=2)
+    assert serve_main(argv) == 0
+    assert "arch=qwen2_7b_smoke  layers=2/3" in capsys.readouterr().out
+
+
+@pytest.mark.parametrize("arch", ["qwen2_7b", "moonshot_v1_16b_a3b",
+                                  "recurrentgemma_9b"])
+def test_init_params_matches_unfused_draw(arch):
+    """The jitted per-leaf init is bit-for-bit the unfused formula it
+    replaced: a float32 normal draw, times the scale, cast."""
+    import zlib
+    import jax.numpy as jnp
+    from repro.models.common import tree_paths
+    cfg = configs.get(arch, smoke=True)
+    spec = model_spec(cfg)
+    rng = jax.random.key(3)
+    got = dict(tree_paths(init_params(rng, spec, dtype=cfg.dtype)))
+    for path, s in tree_paths(spec):
+        if s.init in ("zeros", "ones"):
+            continue
+        key = jax.random.fold_in(
+            rng, zlib.crc32("/".join(path).encode()) % (2 ** 31))
+        scale = (1.0 / np.sqrt(s.shape[-2] if len(s.shape) >= 2
+                               else s.shape[-1])
+                 if s.init == "scaled" else s.scale)
+        want = (jax.random.normal(key, s.shape, jnp.float32)
+                * scale).astype(s.dtype or cfg.dtype)
+        assert got[path].dtype == want.dtype, path
+        np.testing.assert_array_equal(
+            np.asarray(got[path].astype(jnp.float32)),
+            np.asarray(want.astype(jnp.float32)), err_msg=str(path))
+
+
+@pytest.mark.parametrize("env_dir", [None, "given"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR, when set, is the cache and is left
+    alone; unset, the cache is one fixed directory in the checkout."""
+    from repro.launch.compile_cache import CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(CACHE_DIR)
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    try:
+        assert use_compile_cache() == want
+        if env_dir is None:
+            assert jax.config.jax_compilation_cache_dir == want
+            assert CACHE_DIR.parent == Path(__file__).resolve().parents[1]
+        else:
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
