@@ -5,13 +5,19 @@ exports and prints, without importing the serving stack:
 
 * a **TTFT waterfall** — per request: arrival, admission wait, time to
   first token, decode time, all on the engine's virtual clock;
-* a **step-time breakdown** — wall time by engine phase (admit /
-  dispatch / eos_sync / readback) from the ``X`` spans;
+* a **step-time breakdown** — wall time by span from the ``X`` events:
+  the engine's phases (``engine.step`` holding ``engine.admit`` /
+  ``engine.plan`` / ``engine.feed`` / ``engine.avals`` /
+  ``engine.launch`` / ``engine.publish`` / ``engine.finish`` /
+  ``engine.eos_sync``, and ``engine.submit`` / ``engine.readback``
+  outside it) and the store's (``store.register`` / ``store.lookup`` /
+  ``store.insert`` / ``store.complete``);
 * **tier-flow counts** — a Sankey's edge list: how many blocks moved
   device→host, host→disk, disk→device, … and how many died per tier;
 * **top ineffective-hit causes** — the headline analytic: which gaps
   (evicted / demoted-to-host / demoted-to-disk / never-cached) blocked
-  otherwise-warm chains, summed from every ``store.lookup``;
+  otherwise-warm chains, summed from every ``store.lookup`` (a span;
+  older traces hold it as an instant);
 * **bus traffic** by message kind;
 * **latency stats reconstructed from the trace alone** — the same
   TTFT/TPOT percentiles and goodput ``repro.serve.latency_stats``
@@ -157,7 +163,7 @@ def tier_flows(events: List[dict]) -> Dict[tuple, int]:
 def ineffective_causes(events: List[dict]) -> Dict[str, int]:
     causes: Dict[str, int] = defaultdict(int)
     for ev in events:
-        if ev.get("ph") == "i" and ev.get("name") == "store.lookup":
+        if ev.get("ph") in ("X", "i") and ev.get("name") == "store.lookup":
             for cause, n in ((ev.get("args") or {})
                              .get("ineffective", {}) or {}).items():
                 causes[cause] += int(n)
@@ -215,7 +221,7 @@ def print_report(doc: dict, top: int = 20) -> None:
         for name in order:
             rec = steps[name]
             mean = rec["total_us"] / max(rec["n"], 1)
-            print(f"  {name:12s} n={rec['n']:<7} "
+            print(f"  {name:16s} n={rec['n']:<7} "
                   f"total={rec['total_us'] / 1e3:10.2f}ms "
                   f"mean={mean:8.1f}us")
 

@@ -18,6 +18,14 @@ Two clocks, stamped on every event:
 * **wall** — ``time.perf_counter`` seconds since the recorder was built.
   What intra-step phase durations actually cost on this machine.
 
+Every span is also mirrored into the JAX profiler: ``Span.begin()``
+enters a ``jax.profiler.TraceAnnotation`` of the span's name, with its
+scalar args as stats, and ``Span.end()`` adds the end's scalar args and
+leaves it. With no profiler session running that costs one small
+object; with one running the span lands in the ``.xplane.pb`` on the
+device trace's clock, so device idle time can be named by the program
+phase the host was in.
+
 ``export(timebase=...)`` picks which clock becomes the Chrome
 trace-event ``ts``; the other is preserved per-event in ``args`` only
 where the embedder put it there. The export is the standard JSON object
@@ -37,6 +45,8 @@ import math
 import time
 from collections import deque
 from typing import Any, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 # thread-id lanes used by the serve engine's instrumentation (one pid per
 # engine/shard, one lane per subsystem)
@@ -72,13 +82,24 @@ def jsonable(obj):
     return str(obj)
 
 
+def _stats(args: Optional[dict]) -> dict:
+    """The scalar args of a span: what the profiler mirror carries as
+    stats (lists and maps stay in the recorder's own event only)."""
+    if not args:
+        return {}
+    return {k: v for k, v in args.items()
+            if isinstance(v, (bool, int, float, str))}
+
+
 class Span:
     """One ``X`` (complete) event, timed on BOTH clocks between
-    ``begin()`` and ``end()``. Usable as a context manager or via the
-    explicit begin/end pair (the engine's step phases interleave with
-    control flow that a ``with`` block cannot wrap)."""
+    ``begin()`` and ``end()``, and mirrored into the JAX profiler's trace
+    as a ``TraceAnnotation`` over the same interval. Usable as a context
+    manager or via the explicit begin/end pair (the engine's step phases
+    interleave with control flow that a ``with`` block cannot wrap)."""
 
-    __slots__ = ("rec", "name", "cat", "pid", "tid", "args", "_w0", "_v0")
+    __slots__ = ("rec", "name", "cat", "pid", "tid", "args", "_w0", "_v0",
+                 "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str, cat: str,
                  pid: int, tid: int, args: Optional[dict]) -> None:
@@ -86,6 +107,8 @@ class Span:
         self.pid, self.tid, self.args = pid, tid, args
 
     def begin(self) -> "Span":
+        self._ann = TraceAnnotation(self.name, **_stats(self.args))
+        self._ann.__enter__()
         self._w0 = self.rec.wall()
         self._v0 = self.rec.vt
         return self
@@ -94,6 +117,10 @@ class Span:
         rec = self.rec
         if args:
             self.args = {**(self.args or {}), **args}
+            stats = _stats(args)
+            if stats:
+                self._ann.set_metadata(**stats)
+        self._ann.__exit__(None, None, None)
         rec._push({"ph": "X", "name": self.name, "cat": self.cat,
                    "pid": self.pid, "tid": self.tid,
                    "wall": self._w0, "vt": self._v0,

@@ -330,6 +330,19 @@ class ServeEngine:
         self.store.trace_pid = pid
         recorder.vt = self.now
 
+    def detach_trace(self) -> None:
+        """Stop recording: the engine and its store drop the recorder and
+        run on as if ``attach_trace`` had never been called."""
+        self.trace = None
+        self._trace_pid = 0
+        self.store.trace = None
+        self.store.trace_pid = 0
+
+    def _span(self, name: str, args: Optional[dict] = None):
+        """A span on this engine's lane of the attached recorder."""
+        return self.trace.span(name, "engine", self._trace_pid, _TID_ENGINE,
+                               args)
+
     def _aid(self, req: "Request") -> str:
         """Async-track id for a request: pid-qualified, because rids are
         per-engine counters that collide across shards."""
@@ -359,6 +372,14 @@ class ServeEngine:
         backdates the request to its true arrival time when a trace loop
         submits it a fraction of a step late. Raises ``QueueFull`` when
         admission control is on and the queue is at ``max_queue``."""
+        if self.trace is None:
+            return self._submit(prompt, max_new, deadline, arrival)
+        with self._span("engine.submit", {"prompt_tokens": len(prompt)}):
+            return self._submit(prompt, max_new, deadline, arrival)
+
+    def _submit(self, prompt: Sequence[int], max_new: int,
+                deadline: Optional[float],
+                arrival: Optional[float]) -> Request:
         if self.max_queue is not None and len(self.queue) >= self.max_queue:
             self.rejected += 1
             retry_after = self.retry_after()
@@ -475,7 +496,6 @@ class ServeEngine:
         for i in range(self.B):
             if self.slots[i] is not None or not self.queue:
                 continue
-            queued = len(self.queue)
             if any(r.not_before > self.now for r in self.queue):
                 # failover re-admissions wait out their backoff; everyone
                 # else competes normally. This branch is unreachable
@@ -533,10 +553,6 @@ class ServeEngine:
             self.prefill_tokens_skipped += restored
             self.slots[i] = req
             if self.trace is not None:
-                self.trace.instant(
-                    "sched.admit", "sched", self._trace_pid, _TID_SCHED,
-                    args={"rid": req.rid, "slot": i, "pick": pick,
-                          "queued": queued, "restored_tokens": restored})
                 self.trace.async_instant(
                     "req", self._aid(req), "request", self._trace_pid,
                     _TID_REQ, args={"event": "admitted", "slot": i,
@@ -554,16 +570,20 @@ class ServeEngine:
         if trace is None:
             return self._step_inner(None)
         trace.vt = self.now
-        with trace.span("step", "engine", self._trace_pid, _TID_ENGINE,
-                        args={"n": self.steps}):
+        with self._span("engine.step", {"n": self.steps}):
             return self._step_inner(trace)
 
     def _step_inner(self, trace) -> List[Request]:
+        """The step's phases. With a recorder attached each is a span:
+        ``engine.admit``, ``engine.plan``, ``engine.feed`` (host arrays,
+        uploads, the block-table rebuild), ``engine.avals``,
+        ``engine.launch`` (the jitted call), then per request
+        ``engine.publish`` and ``engine.finish``, and ``engine.eos_sync``."""
         pid = self._trace_pid
         if trace is None:
             self._admit()
         else:
-            with trace.span("admit", "engine", pid, _TID_ENGINE):
+            with self._span("engine.admit"):
                 self._admit()
         active = [r for r in self.slots if r is not None]
         if not active:
@@ -573,6 +593,7 @@ class ServeEngine:
                 # to the earliest re-admission so the loop can't spin
                 self.now = min(r.not_before for r in self.queue)
             return []
+        sp = None if trace is None else self._span("engine.plan").begin()
         decoding = [r for r in active if r.pos >= len(r.prompt)]
         prefilling = [r for r in active if r.pos < len(r.prompt)]
         plan = self.scheduler.plan_prefill(prefilling, self.prefill_chunk,
@@ -585,16 +606,12 @@ class ServeEngine:
             r = prefilling[0]
             plan = {r.slot: min(self.prefill_chunk,
                                 len(r.prompt) - r.pos)}
-        if trace is not None:
-            trace.instant(
-                "sched.plan", "sched", pid, _TID_SCHED,
-                args={"plan": {str(s): n for s, n in plan.items()},
-                      "preempted": [r.rid for r in prefilling
-                                    if r.slot not in plan],
-                      "decoding": len(decoding)})
-        dispatch = (trace.span("dispatch", "engine", pid,
-                               _TID_ENGINE).begin()
-                    if trace is not None else None)
+        if sp is not None:
+            sp.end(args={"prefill_slots": len(plan),
+                         "preempted": sum(r.slot not in plan
+                                          for r in prefilling),
+                         "decoding": len(decoding)})
+            sp = self._span("engine.feed").begin()
         feeds: Dict[int, List[int]] = {}
         use_prev = np.zeros((self.B,), bool)
         for r in decoding:
@@ -626,6 +643,7 @@ class ServeEngine:
         args = (self.params,
                 self.pool.buffers if self.paged else self.cache,
                 self._put(tokens), self._put(meta))
+        uploaded = self.paged and self._tables_dirty
         if self.paged:
             if self._tables_dirty:
                 # attention (and the per-layer page gather on the XLA
@@ -642,6 +660,10 @@ class ServeEngine:
                 self._tables_dirty = False
             args += (self._tables_dev,)
         args += (self._prev_out, self._done_dev)
+        if sp is not None:
+            nw = self._tables_dev.shape[1] if self.paged else 0
+            sp.end(args={"S": S, "NW": nw, "tables_uploaded": uploaded})
+            sp = self._span("engine.avals").begin()
         # shapes/shardings of this dispatch, captured BEFORE the call
         # (donation invalidates the KV buffers) — step_hlo() re-lowers
         # from these to expose the compiled step, collectives included
@@ -649,15 +671,19 @@ class ServeEngine:
             lambda a: jax.ShapeDtypeStruct(
                 a.shape, a.dtype, sharding=getattr(a, "sharding", None)),
             args)
+        if sp is not None:
+            sp.end()
+            sp = self._span("engine.launch", {
+                "step": self.steps, "S": S, "NW": nw, "fed": len(fed),
+                "decoding": len(decoding)}).begin()
         out_tok, new_kv, self._done_dev = self._step(*args)
+        if sp is not None:
+            sp.end()
         if self.paged:
             self.pool.buffers = new_kv
         else:
             self.cache = new_kv
         self._prev_out = out_tok
-        if dispatch is not None:
-            dispatch.end(args={"S": S, "fed": len(fed),
-                               "decoding": len(decoding)})
         self.steps += 1
         # prefill attention reads this step: a prompt chunk of ``lens``
         # tokens attends over a context ending at pos + lens, so late
@@ -695,7 +721,11 @@ class ServeEngine:
                             "req", self._aid(r), "request", pid, _TID_REQ,
                             args={"event": "first_token"})
             if r.pos == len(r.prompt):
-                self._publish(r)
+                if trace is None:
+                    self._publish(r)
+                else:
+                    with self._span("engine.publish", {"rid": r.rid}):
+                        self._publish(r)
             if in_decode and r.n_generated >= r.max_new:
                 self._finish(r)
                 finished.append(r)
@@ -708,7 +738,7 @@ class ServeEngine:
             if trace is None:
                 done = np.asarray(jax.device_get(self._done_dev))
             else:
-                with trace.span("eos_sync", "engine", pid, _TID_ENGINE):
+                with self._span("engine.eos_sync"):
                     done = np.asarray(jax.device_get(self._done_dev))
             self.readback_syncs += 1
             for r in decoding:
@@ -720,6 +750,13 @@ class ServeEngine:
     def _finish(self, r: Request) -> None:
         """Complete a request: drain pipelined tokens, truncate at the
         first EOS, retire the store chain, release the slot."""
+        if self.trace is None:
+            self._finish_request(r)
+        else:
+            with self._span("engine.finish", {"rid": r.rid}):
+                self._finish_request(r)
+
+    def _finish_request(self, r: Request) -> None:
         self._drain(r)
         if self.eos_id >= 0 and self.eos_id in r.generated:
             r.generated = r.generated[:r.generated.index(self.eos_id) + 1]
@@ -750,10 +787,8 @@ class ServeEngine:
             if self.trace is None:
                 vals = jax.device_get(r._lazy_out)
             else:
-                with self.trace.span("readback", "engine", self._trace_pid,
-                                     _TID_ENGINE,
-                                     args={"steps": len(r._lazy_out),
-                                           "rid": r.rid}):
+                with self._span("engine.readback",
+                                {"steps": len(r._lazy_out), "rid": r.rid}):
                     vals = jax.device_get(r._lazy_out)
             r.generated.extend(int(v[r.slot]) for v in vals)
             r._lazy_out = []

@@ -164,9 +164,21 @@ class PrefixStore:
         return chain
 
     # ------------------------------------------------------------- requests
+    def _span(self, name: str):
+        """A span on the store's lane of the attached recorder."""
+        return self.trace.span(name, "store", self.trace_pid, _TID_STORE)
+
     def register_request(self, tokens: Sequence[int]) -> int:
         """Announce a request (queued). Each prefix of its chain becomes a
         live peer group until ``complete_request``. Returns a request id."""
+        if self.trace is None:
+            return self._register(tokens)
+        sp = self._span("store.register").begin()
+        rid = self._register(tokens)
+        sp.end(args={"blocks": len(self._pending[rid])})
+        return rid
+
+    def _register(self, tokens: Sequence[int]) -> int:
         rid = next(self._req_ids)
         chain = self._walk(tokens, create=True)
         self._pending[rid] = chain
@@ -198,6 +210,13 @@ class PrefixStore:
         """Retire a request: its chain's references leave the counters, its
         peer-group tasks are garbage-collected from the DAG, and chain
         nodes left with no residency and no references are pruned."""
+        if self.trace is None:
+            self._complete(rid)
+        else:
+            with self._span("store.complete"):
+                self._complete(rid)
+
+    def _complete(self, rid: int) -> None:
         for tid in self._req_tasks.pop(rid, []):
             self.state.on_task_removed(tid)
             self.dag.remove_task(tid, remove_output=True)
@@ -248,13 +267,29 @@ class PrefixStore:
         ancestor is always *more* recent than its descendants: recency
         ties evict leaves before ancestors (the seed's deepest-first rule,
         now expressed through the shared policy clocks — evicting a leaf
-        never orphans resident descendants)."""
+        never orphans resident descendants).
+
+        With a recorder attached the lookup is a ``store.lookup`` span
+        whose args say what it found: chain length, usable blocks, the
+        blocking nodes and the ineffective hits by cause."""
+        if self.trace is None:
+            return self._lookup(tokens, None)
+        sp = self._span("store.lookup").begin()
+        found: Dict[str, Any] = {}
+        usable = self._lookup(tokens, found)
+        sp.end(args=found)
+        return usable
+
+    def _lookup(self, tokens: Sequence[int],
+                found: Optional[Dict[str, Any]]) -> List[Node]:
+        """``lookup``'s work; fills ``found`` with the span's args when
+        it is given."""
         chain = self._walk(tokens)
         usable: List[Node] = []
         touched: List[Node] = []
         broken = False
         cause = None          # first gap's location: the blocking block
-        blocking = [] if self.trace is not None else None
+        blocking = [] if found is not None else None
         ineff: Dict[str, int] = {}
         for node in chain:
             hit = node.resident
@@ -275,12 +310,10 @@ class PrefixStore:
                 touched.append(node)
         for node in reversed(touched):            # leaf first, root last
             self.policy.on_access(node.block_id)
-        if self.trace is not None:
-            self.trace.instant(
-                "store.lookup", "store", self.trace_pid, _TID_STORE,
-                args={"blocks": len(chain), "usable": len(usable),
-                      "broken": broken, "blocking": blocking,
-                      "ineffective": ineff})
+        if found is not None:
+            found.update(blocks=len(chain), usable=len(usable),
+                         broken=broken, blocking=blocking,
+                         ineffective=ineff)
         return usable
 
     # --------------------------------------------------------------- writes
@@ -292,7 +325,24 @@ class PrefixStore:
         ``(position, node) -> payload`` invoked only for blocks that become
         resident — *after* room has been made, so a pool-backed factory
         allocates from indices the evictions just freed.
-        Recency/insertion clocks are stamped leaf→root (see ``lookup``)."""
+        Recency/insertion clocks are stamped leaf→root (see ``lookup``).
+        With a recorder attached the insert is a ``store.insert`` span
+        whose args name the blocks made resident and count the evictions
+        that made room for them."""
+        if self.trace is None:
+            self._insert(tokens, payloads, nbytes_per_block)
+            return
+        sp = self._span("store.insert").begin()
+        evictions = self.metrics_obj.evictions
+        fresh = self._insert(tokens, payloads, nbytes_per_block)
+        sp.end(args={"blocks": [n.uid for n in fresh],
+                     "nbytes_per_block": nbytes_per_block,
+                     "evictions": self.metrics_obj.evictions - evictions})
+
+    def _insert(self, tokens: Sequence[int],
+                payloads: Union[List[Any], Callable[[int, Node], Any]],
+                nbytes_per_block: int) -> List[Node]:
+        """``insert``'s work; returns the blocks it made resident."""
         chain = self._walk(tokens, create=True)
         exclude = {n.block_id for n in chain}
         fresh: List[Node] = []
@@ -316,11 +366,7 @@ class PrefixStore:
                 self.on_status("loaded", node.block_id)
         for node in reversed(fresh):              # leaf first, root last
             self.policy.on_insert(node.block_id)
-        if self.trace is not None and fresh:
-            self.trace.instant(
-                "store.insert", "store", self.trace_pid, _TID_STORE,
-                args={"blocks": [n.uid for n in fresh],
-                      "nbytes_per_block": nbytes_per_block})
+        return fresh
 
     def _pre_insert(self, node: Node) -> None:
         """Hook: ``node`` (non-resident) is about to be (re)inserted.

@@ -53,7 +53,7 @@ Tier 1→2 movement touches no ``DagState`` (the block already left
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Sequence, Set, Tuple, Union
+from typing import Any, Dict, List, Optional, Sequence, Set, Tuple, Union
 
 from .. import quant as quantlib
 from ..core import EvictionIndex, Policy, make_policy
@@ -183,7 +183,8 @@ class TieredKVStore(PrefixStore):
             "key": str(policy.eviction_key(node.block_id, self.state))})
 
     # ---------------------------------------------------------------- reads
-    def lookup(self, tokens: Sequence[int]) -> List[Node]:
+    def _lookup(self, tokens: Sequence[int],
+                found: Optional[Dict[str, Any]]) -> List[Node]:
         """Longest chain resident in *any* tier from the root; demoted
         blocks on it are promoted back to the device pool before the chain
         is returned, so callers always receive tier-0 payloads.
@@ -194,7 +195,7 @@ class TieredKVStore(PrefixStore):
         up to it sits in tier 0 — a partially demoted chain pays the
         promotion copy."""
         if not self.tiered:
-            return super().lookup(tokens)
+            return super()._lookup(tokens, found)
         chain = self._walk(tokens)
         usable: List[Node] = []
         touched_t0: List[Node] = []
@@ -203,7 +204,7 @@ class TieredKVStore(PrefixStore):
         broken = False
         all_t0 = True
         cause = None        # first non-tier-0 node: the chain's blocker
-        blocking = [] if self.trace is not None else None
+        blocking = [] if found is not None else None
         ineff: Dict[str, int] = {}
         for node in chain:
             in_t0 = node.resident
@@ -238,12 +239,10 @@ class TieredKVStore(PrefixStore):
             self.host_policy.on_access(node.block_id)
         for node in reversed(touched_t0):
             self.policy.on_access(node.block_id)
-        if self.trace is not None:
-            self.trace.instant(
-                "store.lookup", "store", self.trace_pid, _TID_STORE,
-                args={"blocks": len(chain), "usable": len(usable),
-                      "broken": broken, "blocking": blocking,
-                      "ineffective": ineff})
+        if found is not None:
+            found.update(blocks=len(chain), usable=len(usable),
+                         broken=broken, blocking=blocking,
+                         ineffective=ineff)
         demoted = [n for n in usable if not n.resident]
         if demoted:
             failed = self._promote(demoted,

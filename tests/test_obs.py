@@ -311,11 +311,14 @@ def _run_mode(cfg, params, reqs, mode, recorder=None):
 # event names every traced run of the mode must produce — doubles as a
 # regression net for the instrumentation sites themselves
 _EXPECT_EVENTS = {
-    "paged": {"step", "dispatch", "store.lookup", "store.insert",
-              "store.evict", "sched.admit", "req"},
-    "tiered": {"step", "store.lookup", "store.demote", "store.promote",
-               "req"},
-    "sharded": {"step", "store.lookup", "req", "bus.status",
+    "paged": {"engine.submit", "engine.step", "engine.admit", "engine.plan",
+              "engine.feed", "engine.avals", "engine.launch",
+              "engine.publish", "engine.finish", "engine.readback",
+              "store.register", "store.lookup", "store.insert",
+              "store.complete", "store.evict", "req"},
+    "tiered": {"engine.step", "store.lookup", "store.demote",
+               "store.promote", "req"},
+    "sharded": {"engine.step", "store.lookup", "req", "bus.status",
                 "bus.status_report", "bus.peer_profile"},
 }
 
@@ -337,6 +340,80 @@ def test_tracing_off_bit_identity(model, mode):
     names = {e["name"] for e in rec.events}
     missing = _EXPECT_EVENTS[mode] - names
     assert not missing, f"instrumentation sites went dark: {missing}"
+
+
+def test_detach_trace_leaves_the_engine_untraced(model):
+    """An engine traced for two steps and then detached serves on exactly
+    as an engine never traced, and records nothing more."""
+    cfg, params = model
+    reqs = workload(cfg.vocab, n_requests=10, n_families=2, seed=3)
+    base = _run_mode(cfg, params, reqs, "paged")
+    blk = _block_nbytes(cfg, params)
+    st = PrefixStore(blk * 10, "lerc", block_tokens=BT)
+    eng = ServeEngine(cfg, params, max_slots=2, max_seq=64, store=st,
+                      prefill_chunk=8, paged=True)
+    rec = TraceRecorder()
+    eng.attach_trace(rec)
+    rs = [eng.submit(r, max_new=MAX_NEW) for r in reqs]
+    eng.step()
+    eng.step()
+    eng.detach_trace()
+    assert eng.trace is None and st.trace is None
+    assert eng._trace_pid == 0 and st.trace_pid == 0
+    emitted = rec.n_emitted
+    assert emitted > 0
+    eng.run()
+    assert rec.n_emitted == emitted
+    assert ([r.generated for r in rs], [st.eviction_log], eng.metrics()) \
+        == base
+
+
+def _nested(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
+
+
+def test_spans_land_in_the_profiler_trace(model, tmp_path):
+    """With a profiler session running, the recorder's spans are written
+    into the profiler's own trace, on its clock: named ``<layer>.<phase>``,
+    nested as the calls nest, with the launch's shape as stats."""
+    from bench.spans import load_spans
+    cfg, params = model
+    reqs = workload(cfg.vocab, n_requests=4, n_families=2, seed=5)
+    blk = _block_nbytes(cfg, params)
+    eng = ServeEngine(cfg, params, max_slots=2, max_seq=64,
+                      store=PrefixStore(blk * 10, "lerc", block_tokens=BT),
+                      prefill_chunk=8, paged=True)
+    eng.attach_trace(TraceRecorder())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for r in reqs:
+            eng.submit(r, max_new=MAX_NEW)
+        for _ in range(6):
+            eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    spans = load_spans(tmp_path)
+    names = {n for n, *_ in spans}
+    assert {"engine.submit", "store.register", "engine.step",
+            "engine.admit", "store.lookup", "engine.plan", "engine.feed",
+            "engine.avals", "engine.launch", "engine.publish",
+            "store.insert"} <= names
+    assert not names & {"step", "admit", "dispatch", "readback"}
+    by = {n: [s for s in spans if s[0] == n] for n in names}
+    for lookup in by["store.lookup"]:
+        admit = [a for a in by["engine.admit"] if _nested(lookup, a)]
+        assert admit, "store.lookup outside every engine.admit"
+        assert any(_nested(admit[0], st) for st in by["engine.step"])
+    for reg in by["store.register"]:
+        assert any(_nested(reg, sub) for sub in by["engine.submit"])
+    launches = by["engine.launch"]
+    assert len(launches) == 6
+    for n, _, _, stats in launches:
+        assert stats["S"] >= 1 and stats["NW"] >= 4
+        assert {"step", "fed", "decoding"} <= set(stats)
+    assert [st["step"] for *_, st in launches] == list(range(6))
+    # the first step prefills a whole chunk of both admitted prompts
+    assert launches[0][3]["S"] == 8 and launches[0][3]["fed"] == 2
 
 
 # TP runs on a dedicated config whose 4 KV heads divide the mesh (the
