@@ -9,7 +9,9 @@ One chip, in one process:
   (a) device: JAX's first device must be a TPU;
   (b) kernel: the paged-attention kernel at qwen2_7b widths (28 query
       heads over 4 KV heads, d_head 128, bf16) against the float32
-      oracle ``kernels.ref.attention_ref``, compiled by Mosaic;
+      oracle ``kernels.ref.attention_ref``, compiled by Mosaic, on rows
+      of mixed lengths, once with table entries past each row's reach
+      naming a pool row of NaN;
   (c) serve: qwen2_7b at its published widths, cut to 14 layers, serves
       8 requests (2 families sharing a 256-token prefix) through
       ``repro.launch.serve``'s own builder: paged engine, LERC prefix
@@ -84,22 +86,30 @@ def kernel_phase() -> None:
 
     B, H, KV, D, bt, NW, NB = 4, 28, 4, 128, BLOCK_TOKENS, 32, 256
     L = NW * bt
-    for S in (1, 8):
+    # first position, mid-block, a block edge, the table's end
+    pos0 = np.array([0, 37, 8 * bt - 1, L - 8], np.int32)
+    # padded: table entries past each row's last visible block name a pool
+    # row of NaN, which the kernel must never read
+    for S, padded in ((1, False), (8, False), (8, True)):
         ks = jax.random.split(jax.random.key(S), 4)
         q = jax.random.normal(ks[0], (B, S, H, D)).astype(jnp.bfloat16)
         kp = jax.random.normal(ks[1], (NB, bt, KV, D)).astype(jnp.bfloat16)
         vp = jax.random.normal(ks[2], (NB, bt, KV, D)).astype(jnp.bfloat16)
         # disjoint shuffled tables: pool row order is unrelated to position
-        tables = jax.random.permutation(ks[3], NB)[:B * NW] \
+        tables = jax.random.permutation(ks[3], NB - 1)[:B * NW] \
             .reshape(B, NW).astype(jnp.int32)
-        # first position, mid-block, a block edge, the table's end
-        pos0 = np.array([0, 37, 8 * bt - 1, L - S], np.int32)
         qpos = jnp.asarray(pos0[:, None] + np.arange(S)[None, :])
+        seen = tables
+        if padded:
+            kp = kp.at[NB - 1].set(jnp.nan)
+            vp = vp.at[NB - 1].set(jnp.nan)
+            seen = jnp.where(jnp.arange(NW)[None, :] > qpos[:, -1:] // bt,
+                             NB - 1, tables)
         hlo = paged_decode_attention.lower(
-            q, kp, vp, tables, qpos).compile().as_text()
+            q, kp, vp, seen, qpos).compile().as_text()
         if "tpu_custom_call" not in hlo:
             fail("paged kernel did not compile to a Mosaic custom call")
-        out = np.asarray(paged_decode_attention(q, kp, vp, tables, qpos),
+        out = np.asarray(paged_decode_attention(q, kp, vp, seen, qpos),
                          np.float32)
         err, worst = 0.0, 0.0
         with jax.default_matmul_precision("highest"):
@@ -116,11 +126,12 @@ def kernel_phase() -> None:
                 err = max(err, float(np.max(diff)))
                 worst = max(worst, float(np.max(
                     diff / (KERNEL_ATOL + KERNEL_RTOL * np.abs(ref)))))
-        print(f"kernel: S={S} bt={bt} max_abs_err={err!r} "
+        print(f"kernel: S={S} bt={bt} padded={padded} max_abs_err={err!r} "
               f"err_over_tol={worst!r} (atol={KERNEL_ATOL} "
               f"rtol={KERNEL_RTOL})")
         if not np.isfinite(worst) or worst > 1.0:
-            fail(f"paged kernel error {err} past tolerance at S={S}")
+            fail(f"paged kernel error {err} past tolerance at S={S}"
+                 f"{' on padded tables' if padded else ''}")
 
 
 def serve_phase(tp: int) -> list:

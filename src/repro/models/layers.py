@@ -142,7 +142,7 @@ def _use_chunked(cfg: ModelConfig, Sq: int) -> bool:
     return Sq > 2048  # auto: full logits past 2k are prohibitive
 
 
-def _use_flash_decode(cfg: ModelConfig) -> bool:
+def use_flash_decode(cfg: ModelConfig) -> bool:
     """Route decode attention through the Pallas flash-decoding kernels
     (plain or paged). "auto" compiles the real Mosaic kernels on TPU and
     keeps the dense-mask XLA path elsewhere — off-TPU the kernels only
@@ -162,7 +162,7 @@ def _paged_attention(cfg: ModelConfig, q, k_pages, v_pages, tables, qpos):
     streams K/V tiles from pool rows named by the (scalar-prefetched)
     table; the XLA path gathers the pages and reuses ``_sdpa`` so the
     numerics match the gather engine's dense decode exactly."""
-    if _use_flash_decode(cfg):
+    if use_flash_decode(cfg):
         from ..kernels import paged_decode_attention
         return paged_decode_attention(q, k_pages, v_pages, tables, qpos,
                                       softcap=cfg.attn_logit_softcap)
@@ -325,7 +325,7 @@ def attention(cfg: ModelConfig, params, x, *, positions, window=None,
             Skv = ck.shape[1]
             base = (cache_pos + 1 if cache_valid_len is None
                     else cache_valid_len)
-            if Sq == 1 and _use_flash_decode(cfg):
+            if Sq == 1 and use_flash_decode(cfg):
                 # flash-decoding: split-K streaming over the valid cache,
                 # no dense (Sq, Skv) mask materialized
                 from ..kernels import decode_attention as _flash_dec
@@ -344,7 +344,7 @@ def attention(cfg: ModelConfig, params, x, *, positions, window=None,
             Skv = ck.shape[1]
             base = (cache_pos + 1 if cache_valid_len is None
                     else cache_valid_len)
-            if Sq == 1 and _use_flash_decode(cfg):
+            if Sq == 1 and use_flash_decode(cfg):
                 # rolling (L) caches pass valid = min(pos+1, window): the
                 # whole wrapped buffer is live, so no window mask applies
                 # to cache slots and slot order stays irrelevant

@@ -53,8 +53,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.paged_attention import rows_walked
 from ..models import decode_step, init_decode_cache
 from ..models.common import ModelConfig
+from ..models.layers import use_flash_decode
 from ..obs.trace import (TID_ENGINE as _TID_ENGINE, TID_REQ as _TID_REQ,
                          TID_SCHED as _TID_SCHED, TID_STORE as _TID_STORE)
 from ..sharding import KVShardCtx, serve_tp_context
@@ -673,9 +675,14 @@ class ServeEngine:
             args)
         if sp is not None:
             sp.end()
-            sp = self._span("engine.launch", {
-                "step": self.steps, "S": S, "NW": nw, "fed": len(fed),
-                "decoding": len(decoding)}).begin()
+            launch = {"step": self.steps, "S": S, "NW": nw, "fed": len(fed),
+                      "decoding": len(decoding)}
+            if self.paged and use_flash_decode(self.cfg):
+                # table rows the paged kernel walks (the XLA fallback
+                # gathers them all): each slot's through its last query
+                launch["kv_rows"] = int(rows_walked(
+                    meta[0] + S - 1, self.store.block_tokens, nw).sum())
+            sp = self._span("engine.launch", launch).begin()
         out_tok, new_kv, self._done_dev = self._step(*args)
         if sp is not None:
             sp.end()

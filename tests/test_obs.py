@@ -372,12 +372,16 @@ def _nested(inner, outer) -> bool:
     return outer[1] <= inner[1] and inner[1] + inner[2] <= outer[1] + outer[2]
 
 
-def test_spans_land_in_the_profiler_trace(model, tmp_path):
+@pytest.mark.parametrize("decode_kernel", ["flash", "xla"])
+def test_spans_land_in_the_profiler_trace(model, tmp_path, decode_kernel):
     """With a profiler session running, the recorder's spans are written
     into the profiler's own trace, on its clock: named ``<layer>.<phase>``,
-    nested as the calls nest, with the launch's shape as stats."""
+    nested as the calls nest, with the launch's shape as stats. The paged
+    kernel's launches also carry the table rows it walks; the XLA
+    fallback, which gathers every row, carries none."""
     from bench.spans import load_spans
     cfg, params = model
+    cfg = cfg.replace(decode_kernel=decode_kernel)
     reqs = workload(cfg.vocab, n_requests=4, n_families=2, seed=5)
     blk = _block_nbytes(cfg, params)
     eng = ServeEngine(cfg, params, max_slots=2, max_seq=64,
@@ -411,9 +415,18 @@ def test_spans_land_in_the_profiler_trace(model, tmp_path):
     for n, _, _, stats in launches:
         assert stats["S"] >= 1 and stats["NW"] >= 4
         assert {"step", "fed", "decoding"} <= set(stats)
+        if decode_kernel == "xla":
+            assert "kv_rows" not in stats
+        else:
+            # at least one table row a slot, at most the whole padded table
+            assert 2 <= stats["kv_rows"] <= 2 * stats["NW"]
     assert [st["step"] for *_, st in launches] == list(range(6))
     # the first step prefills a whole chunk of both admitted prompts
     assert launches[0][3]["S"] == 8 and launches[0][3]["fed"] == 2
+    if decode_kernel == "flash":
+        # from position 0: one table row each
+        assert launches[0][3]["kv_rows"] == 2
+        assert launches[-1][3]["kv_rows"] > 2
 
 
 # TP runs on a dedicated config whose 4 KV heads divide the mesh (the

@@ -59,17 +59,29 @@ def _spec(sharding, shape, dtype):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("bt", [8, 16])
-@pytest.mark.parametrize("S", [1, 8])
-def test_paged_kernel_compiles_for_v5e(one_chip, bt, S):
+PAGED_KERNEL_SHAPES = [
+    # (S, bt, B, NW, NB, H, KV)
+    *(pytest.param(S, bt, 4, 32, 512, H, KV, id=f"{S}-{bt}")
+      for S in (1, 8) for bt in (8, 16)),
+    # the benchmark cells' step: 32 slots, 160-row tables, 10240 pool rows
+    *(pytest.param(S, 16, 32, 160, 10240, H, KV, id=f"cells-S{S}")
+      for S in (1, 8)),
+    # one KV head a device, as the tp=4 shard_map body sees qwen2_7b
+    *(pytest.param(S, 16, 32, 160, 10240, H // KV, 1, id=f"tp4-S{S}")
+      for S in (1, 8)),
+]
+
+
+@pytest.mark.parametrize("S,bt,B,NW,NB,heads,kv_heads", PAGED_KERNEL_SHAPES)
+def test_paged_kernel_compiles_for_v5e(one_chip, S, bt, B, NW, NB, heads,
+                                       kv_heads):
     from repro.kernels.paged_attention import paged_decode_attention
-    B, NB, NW = 4, 512, 32
     f = jax.jit(lambda q, kp, vp, t, qp: paged_decode_attention(
         q, kp, vp, t, qp, interpret=False))
     compiled = f.lower(
-        _spec(one_chip, (B, S, H, D), jnp.bfloat16),
-        _spec(one_chip, (NB, bt, KV, D), jnp.bfloat16),
-        _spec(one_chip, (NB, bt, KV, D), jnp.bfloat16),
+        _spec(one_chip, (B, S, heads, D), jnp.bfloat16),
+        _spec(one_chip, (NB, bt, kv_heads, D), jnp.bfloat16),
+        _spec(one_chip, (NB, bt, kv_heads, D), jnp.bfloat16),
         _spec(one_chip, (B, NW), jnp.int32),
         _spec(one_chip, (B, S), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
